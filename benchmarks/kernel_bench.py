@@ -1,7 +1,8 @@
 """Kernel micro-bench: per-shape wall time and structural counters for the
-IMC matmul kernels (interpret mode on CPU: wall time is indicative only; the
-structural counters are the quantities that transfer to TPU - MXU matmul
-count, HBM bytes per operand class, arithmetic intensity).
+IMC matmul kernels (Pallas runs compiled on a TPU and in interpret mode
+elsewhere, where wall time is indicative only; the structural counters are
+the quantities that transfer to TPU - MXU matmul count, HBM bytes per
+operand class, arithmetic intensity).
 
 Benches both the CURRENT kernel (packed weight planes, one stacked MXU call
 per tile, in-kernel noise) and a frozen copy of the SEED kernel (per-plane
@@ -141,7 +142,7 @@ def _seed_bitserial_matmul(x_codes, w_codes, noise, spec,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile_b, tile_m), lambda b, mm, kk: (b, mm)),
         out_shape=jax.ShapeDtypeStruct((bp, mp), jnp.float32),
-        interpret=True,
+        interpret=jax.default_backend() != "tpu",
     )(*operands)
     return out[:b_sz, :m]
 
@@ -226,8 +227,7 @@ def paged_attention_records(iters: int = 3) -> List[dict]:
             ),
             "kernel": (
                 jax.jit(lambda q_, kn_, vn_: paged_attention_decode(
-                    q_, kn_, vn_, pk, pv, bt, pos_b, scale=scale,
-                    use_pallas=False)),
+                    q_, kn_, vn_, pk, pv, bt, pos_b, scale=scale)),
                 "kernel",
             ),
         }
@@ -293,13 +293,12 @@ def bench_records(iters: int = 3) -> List[dict]:
                 "seed", True,
             ),
             "kernel": (
-                lambda: imc_mvm.imc_bitserial_matmul(xc, wc, None, spec,
-                                                     interpret=True),
+                lambda: imc_mvm.imc_bitserial_matmul(xc, wc, None, spec),
                 "new", False,
             ),
             "kernel_noise": (
                 lambda: imc_mvm.imc_bitserial_matmul(
-                    xc, wc, None, spec_noisy, seed=17, interpret=True
+                    xc, wc, None, spec_noisy, seed=17
                 ),
                 "new", True,
             ),

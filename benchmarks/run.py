@@ -26,7 +26,7 @@ sys.path.insert(
 )
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig4,fig9,fig10,fig11,fig12,fig13,"
@@ -42,8 +42,9 @@ def main() -> None:
     ap.add_argument("--mesh", default=None, metavar="RxC",
                     help="override the serve_sharded suite's device mesh "
                          "(default: the committed baseline mesh, 1x4; the "
-                         "suite runs in a child process that pins 8 "
-                         "host-simulated devices regardless of the parent)")
+                         "suite runs in this process and needs that many "
+                         "devices - on the CPU set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=8)")
     args = ap.parse_args()
     if args.json:
         json_dir = os.path.dirname(os.path.abspath(args.json)) or "."
@@ -54,6 +55,9 @@ def main() -> None:
 
     from benchmarks import kernel_bench, layer_snr, model_energy, roofline, serve_bench
     from benchmarks.paper_figures import ALL as FIG_BENCHES
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.workload_seed is not None:
         serve_bench.SLO_SEED = args.workload_seed
@@ -72,8 +76,7 @@ def main() -> None:
     #       --json BENCH_energy.json
     suites["serve_energy"] = lambda: serve_bench.energy_rows(
         serve_bench.energy_records())
-    # multi-device scaling suite: runs in a child process that pins 8 host
-    # devices, so it works (and gates) under any parent device count
+    # multi-device scaling suite: needs the mesh's devices in this process
     suites["serve_sharded"] = lambda: serve_bench.sharded_rows(
         serve_bench.sharded_records())
     # prefix-sharing paged KV suite: warm (radix prefix cache) vs cold engine
@@ -98,7 +101,7 @@ def main() -> None:
     if only and "serve" in only:
         # the serve bench surface reports energy + multi-device scaling +
         # prefix sharing too: selecting the serve suite pulls in the
-        # (deterministic) serve_energy rollup, the subprocess-isolated
+        # (deterministic) serve_energy rollup, the multi-device
         # serve_sharded comparison, and the serve_prefix warm-vs-cold
         # comparison, so the committed BENCH_serve.json carries all four
         only.add("serve_energy")
@@ -138,6 +141,7 @@ def main() -> None:
         "suites": {},
     }
     print("name,value,derived")
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -150,9 +154,10 @@ def main() -> None:
             else:
                 records = None
                 rows = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report it, run the rest
             print(f"{name}/ERROR,0,{type(e).__name__}: {e}")
             payload["suites"][name] = {"error": f"{type(e).__name__}: {e}"}
+            failed.append(name)
             continue
         dt = time.perf_counter() - t0
         for rname, val, derived in rows:
@@ -171,7 +176,11 @@ def main() -> None:
             json.dump(payload, f, indent=1, sort_keys=True)
             f.write("\n")
         print(f"# wrote {args.json}", file=sys.stderr)
+    if failed:
+        print(f"# suites failed: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

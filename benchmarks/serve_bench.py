@@ -32,17 +32,15 @@ Structural counters reported per configuration:
 CPU wall times are indicative; the structural counters transfer to TPU.
 ``bench_records()`` returns machine-readable dicts (consumed by
 ``benchmarks/run.py --json``); ``run()`` formats them as CSV rows.  The
-committed ``BENCH_serve.json`` baseline is produced with::
+committed ``BENCH_serve.json`` baseline is produced with (the sharded suite
+needs the devices of its mesh)::
 
-    PYTHONPATH=src python benchmarks/run.py --only serve --json BENCH_serve.json
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
+        python benchmarks/run.py --only serve --json BENCH_serve.json
 """
 from __future__ import annotations
 
 import copy
-import json
-import os
-import subprocess
-import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -55,7 +53,7 @@ from repro.core.design import optimize
 from repro.core.mapping import per_token_matmul_shapes
 from repro.core.substrate import AnalyticIMC, BitSerialIMC, substrate_for_design
 from repro.launch.metering import DPMeter, serve_energy_report
-from repro.launch.serve import Engine, Request, needs_exact_prefill, prefill_bucket
+from repro.launch.serve import Engine, Request, needs_exact_prefill, prefill_bucket, serve
 from repro.models import decode_step, init_cache, init_params, prefill
 
 Row = Tuple[str, float, str]
@@ -1025,144 +1023,79 @@ def prefix_records() -> List[dict]:
 
 # the committed sharded scenario: the mixed 4..48 workload served by the
 # single-device engine and by a (1, 4) tensor-parallel engine (musicgen's 4 KV
-# heads head-shard 4 ways: one head per device) inside a child process that
-# pins 8 host-simulated devices - so ANY parent (the single-device tier-1 CI
-# job included) can produce the suite.  Structural fields (per-device KV
-# bytes, greedy-token match) gate exactly; tok/s scaling gates on a generous
-# absolute floor because host-simulated CPU "devices" share one physical
-# socket (all-reduce overhead without any real parallel silicon).
+# heads head-shard 4 ways: one head per device), both in this process - it
+# needs the mesh's devices (on the CPU:
+# XLA_FLAGS=--xla_force_host_platform_device_count=8).  Structural fields
+# (per-device KV bytes, greedy-token match) gate exactly; tok/s scaling gates
+# on a generous absolute floor because host-simulated CPU "devices" share one
+# physical socket (all-reduce overhead without any real parallel silicon).
 SHARDED_MESH = "1x4"
-SHARDED_DEVICES = 8
 # digital + frozen imc_analytic: equivalence across all three substrates
 # (incl. the ~30x-slower bitserial path) is pinned by the slow lane in
 # tests/test_serve_sharded.py; the bench keeps inside the CI budget
 SHARDED_MODES = (None, "imc_analytic")
 
-_SHARDED_CHILD = r"""
-import json
-import os
-import time
 
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ["REPRO_SHARDED_DEVICES"])
-import jax
-import numpy as np
-
-from repro import configs
-from repro.core import substrate as substrate_lib
-from repro.core.imc_linear import IMCConfig
-from repro.launch.mesh import make_serve_mesh, parse_mesh_shape
-from repro.launch.serve import Engine, Request, prefill_bucket, serve
-from repro.models import init_params
-
-ARCH = os.environ["REPRO_SHARDED_ARCH"]
-MESH = os.environ["REPRO_SHARDED_MESH"]
-DEVICES = int(os.environ["REPRO_SHARDED_DEVICES"])
-LENS = [int(x) for x in os.environ["REPRO_SHARDED_LENS"].split(",")]
-GEN = int(os.environ["REPRO_SHARDED_GEN"])
-BATCH = int(os.environ["REPRO_SHARDED_BATCH"])
-REPEATS = int(os.environ["REPRO_SHARDED_REPEATS"])
-MODES = os.environ["REPRO_SHARDED_MODES"].split(",")
-
-
-def mk_requests(cfg, n):
-    rnp = np.random.default_rng(0)
-    return [Request(rid=i,
-                    prompt=rnp.integers(0, cfg.vocab_size,
-                                        LENS[i % len(LENS)]),
-                    max_new=GEN) for i in range(n)]
-
-
-def run_once(engine, cfg, n):
+def _sharded_run(engine, cfg, n):
     engine.decode_calls = engine.decode_steps = 0
     engine.host_transfer_bytes = 0
     engine.prefill_calls = engine.prefill_rows = 0
     engine.finished = []
     t0 = time.perf_counter()
-    out = serve(engine, mk_requests(cfg, n))
+    out = serve(engine, _mk_requests(cfg, MIXED_LENS, n))
     dt = time.perf_counter() - t0
     tokens = sum(len(r.out) for r in out)
     return (tokens / dt if dt > 0 else float("nan"),
             {r.rid: list(r.out) for r in out})
 
 
-records = []
-max_bucket = max(prefill_bucket(l, True, 10 ** 9) for l in LENS)
-cache_len = max_bucket + GEN + 8
-data_ax, model_ax = parse_mesh_shape(MESH)
-for mode in MODES:
-    mode = mode or None
-    n = len(LENS)
-    cfg = configs.get_smoke(ARCH)
-    if mode:
-        cfg = cfg.replace(imc=substrate_lib.as_substrate(
-            IMCConfig(mode=mode, bx=7, bw=7, v_wl=0.7)))
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    if mode:
-        # frozen calibration: batch-composition-invariant IMC forwards (the
-        # precondition for sharded == single-device token identity)
-        ref = np.random.default_rng(1).integers(
-            0, cfg.vocab_size, (2, max(LENS)))
-        cfg = substrate_lib.calibrate_model(cfg, params, [ref])
-    single = Engine(cfg, params, BATCH, cache_len, max_chunk=GEN)
-    run_once(single, cfg, n)  # warmup: compiles excluded from timing
-    tok_s_single, toks_single = max(
-        (run_once(single, cfg, n) for _ in range(REPEATS)),
-        key=lambda t: t[0])
-    mesh = make_serve_mesh(data_ax, model_ax)
-    sharded = Engine(cfg, params, BATCH, cache_len, max_chunk=GEN, mesh=mesh)
-    run_once(sharded, cfg, n)
-    tok_s_sharded, toks_sharded = max(
-        (run_once(sharded, cfg, n) for _ in range(REPEATS)),
-        key=lambda t: t[0])
-    records.append({
-        "bench": "serve_sharded", "arch": ARCH, "config": "tp_engine",
-        "mode": mode or "digital", "substrate": mode or "digital",
-        "decode_attn": sharded.cfg.decode_attn,
-        "mesh_shape": MESH, "devices": DEVICES,
-        "slots": BATCH, "requests": n, "prompt_lens": LENS[:n], "gen": GEN,
-        "tok_s_single": round(tok_s_single, 1),
-        "tok_s_sharded": round(tok_s_sharded, 1),
-        "scaling_tok_s_ratio": round(tok_s_sharded / tok_s_single, 3),
-        "kv_shard_ways": sharded.tp if sharded.kv_shard else 1,
-        "kv_bytes_per_device": sharded.kv_pool_bytes_per_device(),
-        "kv_bytes_total": sharded.kv_pool_bytes(),
-        "token_match": toks_sharded == toks_single,
-    })
-print("SHARDED_JSON " + json.dumps(records))
-"""
+def _best_sharded_run(engine, cfg, n):
+    _sharded_run(engine, cfg, n)  # warmup: compiles excluded from timing
+    return max((_sharded_run(engine, cfg, n) for _ in range(REPEATS)),
+               key=lambda t: t[0])
 
 
 def sharded_records() -> List[dict]:
-    """Run the sharded-vs-single-device comparison in a child process that
-    forces ``SHARDED_DEVICES`` host devices (XLA pins the device count at
-    backend init, so the parent's count - 1 in tier-1 CI - cannot be
-    changed in-process)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)  # the child pins its own device count
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.update(
-        REPRO_SHARDED_ARCH=ARCH,
-        REPRO_SHARDED_MESH=SHARDED_MESH,
-        REPRO_SHARDED_DEVICES=str(SHARDED_DEVICES),
-        REPRO_SHARDED_LENS=",".join(str(l) for l in MIXED_LENS),
-        REPRO_SHARDED_GEN=str(GEN),
-        REPRO_SHARDED_BATCH=str(BATCH),
-        REPRO_SHARDED_REPEATS=str(REPEATS),
-        REPRO_SHARDED_MODES=",".join(m or "" for m in SHARDED_MODES),
-    )
-    proc = subprocess.run([sys.executable, "-c", _SHARDED_CHILD],
-                          capture_output=True, text=True, env=env, cwd=root,
-                          timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            "sharded serve child failed:\n--- stdout ---\n"
-            f"{proc.stdout[-2000:]}\n--- stderr ---\n{proc.stderr[-2000:]}")
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith("SHARDED_JSON ")]
-    return json.loads(lines[-1][len("SHARDED_JSON "):])
+    """The sharded-vs-single-device comparison, in this process: it needs
+    the ``SHARDED_MESH`` devices (``make_serve_mesh`` raises otherwise)."""
+    from repro.core.substrate import calibrate_model
+    from repro.launch.mesh import make_serve_mesh, parse_mesh_shape
+
+    mesh = make_serve_mesh(*parse_mesh_shape(SHARDED_MESH))
+    n = len(MIXED_LENS)
+    max_bucket = max(prefill_bucket(l, True, 10 ** 9) for l in MIXED_LENS)
+    cache_len = max_bucket + GEN + 8
+    records = []
+    for mode in SHARDED_MODES:
+        cfg = _mk_cfg(mode)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        if mode:
+            # frozen calibration: batch-composition-invariant IMC forwards
+            # (the precondition for sharded == single-device token identity)
+            ref = np.random.default_rng(1).integers(
+                0, cfg.vocab_size, (2, max(MIXED_LENS)))
+            cfg = calibrate_model(cfg, params, [ref])
+        single = Engine(cfg, params, BATCH, cache_len, max_chunk=GEN)
+        tok_s_single, toks_single = _best_sharded_run(single, cfg, n)
+        sharded = Engine(cfg, params, BATCH, cache_len, max_chunk=GEN,
+                         mesh=mesh)
+        tok_s_sharded, toks_sharded = _best_sharded_run(sharded, cfg, n)
+        records.append({
+            "bench": "serve_sharded", "arch": ARCH, "config": "tp_engine",
+            "mode": mode or "digital", "substrate": mode or "digital",
+            "decode_attn": sharded.cfg.decode_attn,
+            "mesh_shape": SHARDED_MESH, "devices": len(jax.devices()),
+            "slots": BATCH, "requests": n, "prompt_lens": MIXED_LENS[:n],
+            "gen": GEN,
+            "tok_s_single": round(tok_s_single, 1),
+            "tok_s_sharded": round(tok_s_sharded, 1),
+            "scaling_tok_s_ratio": round(tok_s_sharded / tok_s_single, 3),
+            "kv_shard_ways": sharded.tp if sharded.kv_shard else 1,
+            "kv_bytes_per_device": sharded.kv_pool_bytes_per_device(),
+            "kv_bytes_total": sharded.kv_pool_bytes(),
+            "token_match": toks_sharded == toks_single,
+        })
+    return records
 
 
 def sharded_rows(records: List[dict]) -> List[Row]:

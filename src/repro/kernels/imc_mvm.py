@@ -29,11 +29,13 @@ TPU mapping notes (hardware adaptation, DESIGN.md SS3):
     count per tile from Bw*Bx to 1 and amortizes per-op overhead (the
     dominant cost in interpret mode, and scheduling overhead on TPU).
   * the per-plane nonlinearities (clip, noise add, ADC) are VPU elementwise
-    ops applied to the whole stacked (Bx*B_t, Bw*M_t) accumulator at once;
-    recombination walks the 36 sub-tiles in the oracle's i-outer/j-inner
-    order within each bank (cross-bank f32 accumulation order differs:
-    per-bank local sum, then in-place add - single-bank shapes match ref.py's
-    rounding exactly, multi-bank shapes to allclose tolerance).
+    ops applied one weight-plane slab (Bx*B_t, M_t) of the stacked
+    accumulator at a time, so the live noise draws fit the chip's scoped
+    VMEM; recombination walks the Bw*Bx sub-tiles in the oracle's
+    i-outer/j-inner order within each bank (cross-bank f32 accumulation
+    order differs: per-bank local sum, then in-place add - single-bank
+    shapes match ref.py's rounding exactly, multi-bank shapes to allclose
+    tolerance).
   * analog noise never touches HBM: the kernel draws it in-register, either
     from the TPU hardware PRNG (pltpu.prng_seed / prng_random_bits, seeded
     per (b, m, bank) grid step) or - in interpret/CPU mode - from the
@@ -61,11 +63,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only primitives (hardware PRNG); absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import prng
 from repro.kernels.ref import (
@@ -86,9 +84,7 @@ def _interpret_default() -> bool:
 
 def _hw_prng_available(interpret: bool) -> bool:
     """Use the TPU hardware PRNG only when actually compiling for TPU."""
-    return (not interpret) and pltpu is not None and (
-        jax.default_backend() == "tpu"
-    )
+    return (not interpret) and jax.default_backend() == "tpu"
 
 
 def _tpu_normal(shape):  # pragma: no cover - requires real TPU
@@ -106,9 +102,8 @@ def _fold_seed(seed, *ids):
     multiples of each other collide across grid steps, handing different
     tiles bit-identical hardware-PRNG streams).
     """
-    return jax.lax.bitcast_convert_type(
-        prng.hash_u32(seed, *ids), jnp.int32
-    )
+    # a same-width integer convert: Mosaic bitcasts vectors only
+    return prng.hash_u32(seed, *ids).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -170,39 +165,40 @@ def _bitserial_kernel(
     # ONE MXU call for all Bw*Bx plane dot products (counts)
     dp = jnp.dot(xs, wp, preferred_element_type=jnp.float32)
 
-    # VPU epilogue on the whole stacked tile: headroom clip -> noise -> ADC
+    # VPU epilogue, one weight-plane slab (Bx*B_t, M_t) at a time: headroom
+    # clip -> noise -> ADC -> signed power-of-two recombination, walking
+    # sub-tiles in the oracle's i-outer/j-inner order (within a bank; the
+    # cross-bank accumulation order differs - per-bank local sum, then
+    # in-place add to o_ref).  Slab-wise noise keeps the live VMEM set to one
+    # plane's draws instead of all Bw*Bx of them.
     dp = jnp.minimum(dp, spec.k_h)
-    if has_noise:
-        if hw_prng:  # pragma: no cover - requires real TPU
-            pltpu.prng_seed(
-                _fold_seed(seed_ref[0, 0], pl.program_id(0),
-                           pl.program_id(1), bank)
-            )
-            z = _tpu_normal(dp.shape)
-        else:
-            # deterministic counter PRNG over GLOBAL (bank, plane, b, m)
-            # indices: tile-layout independent, bit-exact vs ref.py
-            row = jax.lax.broadcasted_iota(jnp.int32, dp.shape, 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, dp.shape, 1)
-            b_g = pl.program_id(0) * tile_b + row % tile_b
-            m_g = pl.program_id(1) * tile_m + col % tile_m
-            plane = (col // tile_m) * bx + row // tile_b  # p = i*Bx + j
-            z = prng.counter_normal(
-                seed_ref[0, 0], prng.TAG_BITSERIAL, bank, plane, b_g, m_g
-            )
-        dp = jnp.maximum(dp + spec.sigma_noise * z, 0.0)
-    if spec.apply_adc:
-        dp = adc_transfer(dp, spec.b_adc, spec.v_c)
-
-    # signed power-of-two recombination, walking sub-tiles in the oracle's
-    # i-outer/j-inner order (within a bank; the cross-bank accumulation order
-    # differs - per-bank local sum, then in-place add to o_ref)
+    if has_noise and hw_prng:
+        pltpu.prng_seed(
+            _fold_seed(seed_ref[0, 0], pl.program_id(0), pl.program_id(1),
+                       bank)
+        )
     acc = jnp.zeros((tile_b, tile_m), jnp.float32)
     for i in range(bw):
+        slab = dp[:, i * tile_m:(i + 1) * tile_m]
+        if has_noise:
+            if hw_prng:
+                z = _tpu_normal(slab.shape)
+            else:
+                # deterministic counter PRNG over GLOBAL (bank, plane, b, m)
+                # indices: tile-layout independent, bit-exact vs ref.py
+                row = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 1)
+                b_g = pl.program_id(0) * tile_b + row % tile_b
+                m_g = pl.program_id(1) * tile_m + col
+                plane = i * bx + row // tile_b  # p = i*Bx + j
+                z = prng.counter_normal(
+                    seed_ref[0, 0], prng.TAG_BITSERIAL, bank, plane, b_g, m_g
+                )
+            slab = jnp.maximum(slab + spec.sigma_noise * z, 0.0)
+        if spec.apply_adc:
+            slab = adc_transfer(slab, spec.b_adc, spec.v_c)
         for j in range(bx):
-            blk = dp[j * tile_b:(j + 1) * tile_b,
-                     i * tile_m:(i + 1) * tile_m]
-            acc = acc + (ww[i] * xw[j]) * blk
+            acc = acc + (ww[i] * xw[j]) * slab[j * tile_b:(j + 1) * tile_b]
     o_ref[...] += acc
 
 

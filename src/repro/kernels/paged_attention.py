@@ -65,11 +65,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only grid spec (scalar prefetch); absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -177,10 +173,11 @@ def _paged_kernel(bt_ref, wdest_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref,
     # gated on the write mask: an inactive row's write goes to garbage, so
     # its tail lane must keep attending the STALE pool value (gather-path
     # semantics; the row's output is discarded by the engine anyway)
-    row = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
+    # (masks are built at full rank: Mosaic cannot reshape an i1 vector)
+    row = jax.lax.broadcasted_iota(jnp.int32, k_blk.shape, 0)
     sel = (row == pos % bs) & (j == pos // bs) & (act_ref[b] != 0)
-    k_blk = jnp.where(sel[:, None, None], kn_ref[0][None], k_blk)
-    v_blk = jnp.where(sel[:, None, None], vn_ref[0][None], v_blk)
+    k_blk = jnp.where(sel, kn_ref[0][None], k_blk)
+    v_blk = jnp.where(sel, vn_ref[0][None], v_blk)
     # aliased write-back: the tail step persists the overlay into the slot's
     # tail block; every other step's destination is garbage block 0
     opk_ref[0] = k_blk
@@ -190,8 +187,8 @@ def _paged_kernel(bt_ref, wdest_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref,
     s = jnp.einsum("hgd,khd->hgk", qf, k_blk.astype(jnp.float32)) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    valid = (j * bs + row) <= pos
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
+    k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(k_pos <= pos, s, NEG_INF)
     m = m_scr[...]
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     p = jnp.exp(s - m_new[..., None])
@@ -209,9 +206,6 @@ def _paged_kernel(bt_ref, wdest_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref,
 def _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, dest, off, act,
                    scale: float, softcap: Optional[float], interpret: bool):
     """pallas_call wrapper: scalar-prefetched block table + write routing."""
-    if pltpu is None:  # pragma: no cover - CPU builds without pallas.tpu
-        return _decode_jax(q, k_new, v_new, pk, pv, bt, pos_b, dest, off,
-                           scale, softcap)
     b, max_blocks = bt.shape
     bs, hkv, hd = pk.shape[1], pk.shape[2], pk.shape[3]
     g = q.shape[2]

@@ -26,6 +26,7 @@ ops inside Pallas and to XLA integer ops in the oracles.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # domain-separation tags (first counter field) so the two kernels never share
@@ -63,7 +64,10 @@ def uniform_from_bits(bits, open_zero: bool = False):
 
     ``open_zero=True`` maps to (0, 1] (safe under log); otherwise [0, 1).
     """
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32)
+    # the 24-bit value is a non-negative int32: convert through int32, since
+    # Mosaic has no uint32 -> f32 cast (the values are identical either way)
+    u = jax.lax.bitcast_convert_type(bits >> jnp.uint32(8), jnp.int32)
+    u = u.astype(jnp.float32)
     if open_zero:
         u = u + 1.0
     return u * jnp.float32(2.0**-24)

@@ -4,7 +4,7 @@ os.environ["XLA_FLAGS"] = (
     + os.environ.get("XLA_FLAGS_EXTRA", "")
 )
 # ^ MUST precede any jax import: jax locks the device count on first init.
-#   512 placeholder host devices let jax.make_mesh build the production
+#   512 placeholder host devices let launch.mesh.make_mesh build the production
 #   (2, 16, 16) multi-pod mesh on a single CPU for the dry-run.
 
 """Multi-pod dry-run (deliverable (e)).
@@ -38,7 +38,7 @@ from repro import configs
 from repro.configs.shapes import SHAPES, input_specs, shape_applicable
 from repro.launch import hlo_analysis
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import init_cache, init_params
 
 # v5e-ish hardware constants for the roofline (EXPERIMENTS.md SSRoofline)
@@ -106,8 +106,7 @@ def make_layout_mesh(layout: str):
     Total chips must be 256 (single-pod) or 512 (multi-pod)."""
     dims = tuple(int(x) for x in layout.split("x"))
     axes = ("pod", "data", "model") if len(dims) == 3 else ("data", "model")
-    import jax as _jax
-    return _jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def run_cell(

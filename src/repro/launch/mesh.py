@@ -18,17 +18,22 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The dry-run / deployment mesh: (16, 16) or (2, 16, 16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A mesh whose axes are all ``Auto``: shardings propagate through the
+    program as GSPMD places them (``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which the embedding gather needs an ``out_sharding``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: Optional[int] = None):
@@ -53,7 +58,7 @@ def make_host_mesh(model_axis: Optional[int] = None):
         model_axis = min(model_axis, n)
         while n % model_axis != 0:
             model_axis -= 1
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def parse_mesh_shape(spec: str) -> Tuple[int, int]:

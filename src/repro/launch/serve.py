@@ -76,7 +76,7 @@ Robustness (drift + faults):
   failure isolation    a request that cannot be admitted (oversized) or whose
                        prefill keeps failing retires with a per-request
                        ``error`` status instead of killing the engine; a
-                       transient ``XlaRuntimeError`` on a decode chunk is
+                       transient ``JaxRuntimeError`` on a decode chunk is
                        retried once (the ``runtime.fault`` retry idiom) and,
                        if it persists, fails only the requests in flight.
 
@@ -152,6 +152,7 @@ from repro.launch.sharding import (
 from repro.models import decode_step, init_paged_cache, init_params, prefill
 from repro.runtime import drift as drift_lib
 from repro.runtime import fault as fault_lib
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.prefix_cache import PrefixCache
 
 log = logging.getLogger("repro.serve")
@@ -544,6 +545,7 @@ class Engine:
         self._prefill_fns: Dict[Tuple[int, int, bool, Any], Any] = {}
         self._decode_fns: Dict[Tuple[int, bool, Any], Any] = {}
         self._warm_fns: Dict[Tuple[int, Any], Any] = {}
+        self._logits_fn = None
         if self.tp > 1:
             rep = self._rep_sharding
             self._insert_fn = self._with_rules(jax.jit(
@@ -1576,11 +1578,43 @@ class Engine:
                 out_shardings=(self._cache_shardings, rep, rep, rep)))
         return jax.jit(chunk)
 
+    def compiled_decode_text(self, n_steps: int) -> str:
+        """Optimized HLO of the fused decode chunk of ``n_steps`` steps at
+        the engine's current shapes - the program ``decode_chunk`` runs -
+        for checking which kernels the device program holds.  Single-device
+        engines only (the sharded chunk is wrapped in its axis rules)."""
+        if self.tp > 1:
+            raise ValueError("compiled_decode_text needs a single-device "
+                             "engine")
+        fn = self._decode_fns.get((n_steps, False, self.substrate.trace_key))
+        if fn is None:
+            fn = self._make_decode(n_steps)
+        active = jnp.zeros((self.batch_slots,), bool)
+        return fn.lower(self.params, self.cache, self.last_token, self.pos,
+                        active, self.rng, self._calib).compile().as_text()
+
+    def prompt_logits(self, prompt) -> np.ndarray:
+        """f32 logits at the last position of a lone prefill of ``prompt`` -
+        the distribution the first generated token is the argmax of - under
+        the engine's placement and axis rules (to compare engines, e.g. a
+        sharded one against a single device)."""
+        if self._logits_fn is None:
+            cfg = self.cfg
+
+            def last_logits(params, toks, calib):
+                logits, _ = prefill(params, _cfg_with_calibration(cfg, calib),
+                                    toks, cache_len=toks.shape[1])
+                return logits[0, -1].astype(jnp.float32)
+
+            self._logits_fn = self._with_rules(jax.jit(last_logits))
+        toks = jnp.asarray(np.asarray(prompt, np.int32)[None])
+        return np.asarray(self._logits_fn(self.params, toks, self._calib))
+
     def decode_chunk(self, n_steps: Optional[int] = None) -> np.ndarray:
         """Run ``n_steps`` fused decode steps; returns the (slots, T) token
         block (the single device->host transfer of the chunk).
 
-        A transient device error (``XlaRuntimeError``) is retried once via
+        A transient device error (``JaxRuntimeError``) is retried once via
         the shared ``runtime.fault`` idiom - the chunk function is pure, so
         the re-run is exact; if the error persists, only the requests in
         flight retire with an error status and the engine survives."""
@@ -1760,7 +1794,11 @@ def serve_slo(engine: Engine, requests: List[Request], policy=None,
     return engine.finished
 
 
-def main(argv=None):
+def main(argv=None, on_engine: Optional[Callable[[Engine], None]] = None):
+    """Serve seeded traffic from the command line; returns the finished
+    requests.  ``on_engine`` is called with the engine once it is built,
+    before any request is served.  Raises ``RuntimeError`` when a request of
+    the offline replay finishes with an error status."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCH_NAMES))
     ap.add_argument("--smoke", action="store_true")
@@ -1879,6 +1917,7 @@ def main(argv=None):
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     cfg = cfg.replace(decode_attn=args.decode_attn)
@@ -1965,6 +2004,8 @@ def main(argv=None):
                     drift_monitor=monitor, alloc_policy=args.alloc,
                     clock=clock, drift_pause_depth=args.drift_pause_depth,
                     mesh=mesh, prefix_cache=args.prefix_cache)
+    if on_engine is not None:
+        on_engine(engine)
 
     if args.workload != "none":
         from repro.launch.metering import format_slo_summary, slo_summary
@@ -2048,8 +2089,9 @@ def main(argv=None):
     )
     failed = [r for r in finished if r.error is not None]
     if failed:
-        log.warning("%d request(s) finished with an error status: %s",
-                    len(failed), [r.rid for r in failed])
+        raise RuntimeError(
+            f"{len(failed)} request(s) finished with an error status: "
+            + "; ".join(f"request {r.rid}: {r.error}" for r in failed))
     if args.prefix_cache:
         ps = engine.prefix_stats()
         print(f"prefix cache: hit_rate={ps['hit_rate']:.2f} "

@@ -2,7 +2,7 @@
 restart, preemption-safe save, and elastic re-mesh.
 
 On a real multi-pod deployment the failure modes are: host crash (process
-exits -> restart from checkpoint), device error (XlaRuntimeError -> retry the
+exits -> restart from checkpoint), device error (JaxRuntimeError -> retry the
 step, then restart), straggler (step exceeds deadline -> raise, coordinator
 reschedules), and preemption (SIGTERM -> synchronous final save).  On CPU we
 exercise the same code paths with injected failures (tests/test_fault.py).
@@ -24,21 +24,13 @@ from repro.checkpoint import manager as ckpt
 
 log = logging.getLogger("repro.fault")
 
-try:  # the jaxlib runtime's catch-all for device-side faults
-    from jaxlib.xla_extension import XlaRuntimeError as _XlaRuntimeError
-except Exception:  # pragma: no cover - jaxlib layout drift
-
-    class _XlaRuntimeError(Exception):
-        pass
-
-
 #: exception types a retry is worth attempting for: device-side runtime
 #: errors (OOM blips, transient fabric faults), not Python-level bugs
-TRANSIENT_ERROR_TYPES = (_XlaRuntimeError,)
+TRANSIENT_ERROR_TYPES = (jax.errors.JaxRuntimeError,)
 
 
 def is_transient_device_error(e: BaseException) -> bool:
-    """True for device-runtime errors worth a retry (``XlaRuntimeError`` and
+    """True for device-runtime errors worth a retry (``JaxRuntimeError`` and
     subclasses).  Python-level exceptions - shape errors, assertion failures,
     programming bugs - are NOT transient: retrying them only hides the bug."""
     return isinstance(e, TRANSIENT_ERROR_TYPES)

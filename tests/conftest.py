@@ -5,8 +5,13 @@ import os
 # make sure no leaked XLA_FLAGS turn tests multi-device
 os.environ.pop("XLA_FLAGS", None)
 
+import jax
 import numpy as np
 import pytest
+
+# tests compile afresh and write nothing into the checkout: entry points under
+# test (serve.main) point JAX's persistent compilation cache at <repo>/.jax_cache
+jax.config.update("jax_enable_compilation_cache", False)
 
 try:
     from hypothesis import settings as _hyp_settings
@@ -34,8 +39,6 @@ def _bounded_xla_executable_accumulation():
     segfault inside backend_compile (deterministically, on the next scan
     compile).  Per-module clearing bounds the live set; tests never share
     compiled functions across modules, so this only costs recompiles."""
-    import jax
-
     jax.clear_caches()
     yield
 

@@ -84,11 +84,11 @@ COMPRESSION_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.optim import compressed_psum, init_residual
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 rng = np.random.default_rng(0)
 g_all = jnp.asarray(rng.normal(size=(8, 128)), jnp.float32)
 res = jnp.zeros((8, 128), jnp.float32)
@@ -97,7 +97,7 @@ def f(g, r):
     out, new_r = compressed_psum({"g": g[0]}, {"g": r[0]}, "pod")
     return out["g"][None], new_r["g"][None]
 
-fm = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
+fm = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
 out, new_res = fm(g_all, res)
 true_mean = jnp.mean(g_all, axis=0)
 err = float(jnp.max(jnp.abs(out[0] - true_mean)))

@@ -178,12 +178,12 @@ def test_compression_error_feedback_single_device():
     """Under a 1-member axis, compressed_psum must reproduce the gradient up
     to int8 quantization, and error feedback must keep the *running sum*
     accurate (residual carries over)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
     from repro.optim import compressed_psum, init_residual
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(64,)),
                           jnp.float32)}
     r = init_residual(g)
@@ -191,7 +191,7 @@ def test_compression_error_feedback_single_device():
     def f(g, r):
         return compressed_psum(g, r, "pod")
 
-    fm = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     acc = jnp.zeros(64)
     acc_true = jnp.zeros(64)
     for i in range(20):
