@@ -28,6 +28,7 @@ substrate - bit-for-bit the historical behaviour.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
@@ -202,7 +203,18 @@ def linear(
     ``max|x|`` / ``std(y)`` statistics and make the call
     batch-composition-invariant.  ``site=None`` uses the substrate's base
     config and the calibration's ``"*"`` fallback entry.
+
+    The call runs under a named scope of its ``site``; on every substrate
+    but ``digital``, the substrate's emulation around the matmul (operand
+    statistics and quantization, the noise and ADC epilogue) runs in an
+    inner ``imc`` scope, while the matmul itself stays in the site's scope.
+    A profile's op names carry both.
     """
+    with jax.named_scope(site) if site else contextlib.nullcontext():
+        return _linear(w, x, cfg, rng, bias, dot_general, site)
+
+
+def _linear(w, x, cfg, rng, bias, dot_general, site):
     from repro.core import substrate as substrate_lib
 
     sub = substrate_lib.as_substrate(cfg)
@@ -219,10 +231,11 @@ def linear(
         # calibration pass (eager): record this site's operand ranges, then
         # execute the cheap noiseless fakequant proxy - same ranges as the
         # real substrate without paying for noise draws / bit-serial planes
-        x_max = _dynamic_max(x)
-        w_max = _dynamic_max(w)
-        xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
-        wq = _fq_ste(w, cfg.bw, True, w_max)
+        with jax.named_scope("imc"):
+            x_max = _dynamic_max(x)
+            w_max = _dynamic_max(w)
+            xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
+            wq = _fq_ste(w, cfg.bw, True, w_max)
         y = jnp.einsum("...k,km->...m", xq, wq)
         rec.observe(site or substrate_lib.DEFAULT_SITE, x, w, y=y)
         return y if bias is None else y + bias
@@ -242,42 +255,50 @@ def linear(
 
     stats = sub.site_stats(site)  # None => dynamic per-batch statistics
     if stats is None:
-        x_max = _dynamic_max(x)
-        w_max = _dynamic_max(w)
+        with jax.named_scope("imc"):
+            x_max = _dynamic_max(x)
+            w_max = _dynamic_max(w)
     else:
         x_max = stats.x_max
         w_max = stats.w_max
 
     if cfg.mode == "fakequant":
-        xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
-        wq = _fq_ste(w, cfg.bw, True, w_max)
+        with jax.named_scope("imc"):
+            xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
+            wq = _fq_ste(w, cfg.bw, True, w_max)
         y = jnp.einsum("...k,km->...m", xq, wq)
         _shadow_note(y)
         return y if bias is None else y + bias
 
     if cfg.mode == "imc_analytic":
         n = x.shape[-1]
-        xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
-        wq = _fq_ste(w, cfg.bw, True, w_max)
+        with jax.named_scope("imc"):
+            xq = _fq_ste(x, cfg.bx, cfg.x_signed, x_max)
+            wq = _fq_ste(w, cfg.bw, True, w_max)
         y = jnp.einsum("...k,km->...m", xq, wq)
         _shadow_note(y)
-        if stats is None:
-            sigma_yo = jax.lax.stop_gradient(jnp.std(y) + 1e-9)
-        else:
-            sigma_yo = stats.sigma_yo
-        snr_a_db = cfg.resolved_snr_a_db(n)
-        sigma_a = sigma_yo * 10.0 ** (-snr_a_db / 20.0)
-        if rng is not None:
-            y = y + sigma_a * jax.random.normal(rng, y.shape, dtype=y.dtype)
-        # MPC output ADC: clip at zeta*sigma, quantize with B_ADC bits (STE)
-        b_adc = cfg.resolved_b_adc(n)
-        y_c = cfg.y_clip_sigmas * sigma_yo
-        y = _fq_ste(jnp.clip(y, -y_c, y_c), b_adc, True, y_c)
+        with jax.named_scope("imc"):
+            if stats is None:
+                sigma_yo = jax.lax.stop_gradient(jnp.std(y) + 1e-9)
+            else:
+                sigma_yo = stats.sigma_yo
+            snr_a_db = cfg.resolved_snr_a_db(n)
+            sigma_a = sigma_yo * 10.0 ** (-snr_a_db / 20.0)
+            if rng is not None:
+                y = y + sigma_a * jax.random.normal(rng, y.shape,
+                                                    dtype=y.dtype)
+            # MPC output ADC: clip at zeta*sigma, quantize with B_ADC bits
+            # (STE)
+            b_adc = cfg.resolved_b_adc(n)
+            y_c = cfg.y_clip_sigmas * sigma_yo
+            y = _fq_ste(jnp.clip(y, -y_c, y_c), b_adc, True, y_c)
         return y if bias is None else y + bias
 
     if cfg.mode == "imc_bitserial":
         from repro.kernels import ops as kops
 
+        # the kernel is this substrate's matmul: its plane products and
+        # per-plane ADC stay in the site's scope
         n = x.shape[-1]
         mcfg = kops.matmul_config_from_imc(cfg, n)
         lead = x.shape[:-1]

@@ -253,6 +253,7 @@ def _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, dest, off, act,
         # operand indices count the 4 scalar-prefetch args: pk = 7, pv = 8
         input_output_aliases={7: 1, 8: 2},
         interpret=interpret,
+        name="paged_attention",
     )(bt, wdest, pos_b, act, q, k_new, v_new, pk, pv)
     return ctx, opk, opv
 
@@ -262,6 +263,7 @@ def _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, dest, off, act,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("paged_attention")
 def paged_attention_decode(
     q: jax.Array,       # (B, Hkv, G, hd) grouped queries (rope applied)
     k_new: jax.Array,   # (B, Hkv, hd) new token K (any float dtype)
@@ -283,7 +285,9 @@ def paged_attention_decode(
     scattered into the pools per the garbage-block-0 contract.  Dispatch
     mirrors ``kernels/prng.py``: the Pallas kernel on TPU, the pure-JAX
     streamed fallback (identical math) elsewhere; ``use_pallas``/``interpret``
-    force either path for the interpret-mode equivalence tests.
+    force either path for the interpret-mode equivalence tests.  Both paths
+    run under the ``paged_attention`` named scope, which a profile's op
+    names carry.
     """
     pos_b = pos_b.astype(jnp.int32)
     # cast ONCE to the pool dtype before both the scatter and the overlay so

@@ -114,6 +114,7 @@ def init_paged_cache(cfg: ArchConfig, batch: int, cache_len: int,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("embed")
 def _embed_inputs(params, cfg: ArchConfig, tokens, prefix_embeds, positions):
     x = params["embed"][tokens]  # (B, S, d)
     if cfg.emb_scale:
@@ -128,6 +129,7 @@ def _embed_inputs(params, cfg: ArchConfig, tokens, prefix_embeds, positions):
     return ws(x, "act_btd")
 
 
+@jax.named_scope("head")
 def _head(params, cfg: ArchConfig, x):
     x = apply_norm(params["final_norm"], x, cfg.norm_kind)
     if cfg.tie_embeddings:

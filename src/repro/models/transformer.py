@@ -119,18 +119,20 @@ def init_block_cache_paged(cfg: ArchConfig, kind: str, batch: int,
 
 def _mlp_half(p, x, cfg: ArchConfig, rng):
     h = apply_norm(p["norm2"], x, cfg.norm_kind)
-    if cfg.n_experts > 0:
-        out, aux = moe_lib.apply_moe(
-            p["moe"], h, cfg.n_experts, cfg.top_k, cfg.capacity_factor,
-            cfg.moe_group_size, cfg.mlp_kind, cfg.imc, rng,
-        )
-    else:
-        out, aux = apply_mlp(p["mlp"], h, cfg.mlp_kind, cfg.imc, rng), 0.0
+    with jax.named_scope("mlp"):
+        if cfg.n_experts > 0:
+            out, aux = moe_lib.apply_moe(
+                p["moe"], h, cfg.n_experts, cfg.top_k, cfg.capacity_factor,
+                cfg.moe_group_size, cfg.mlp_kind, cfg.imc, rng,
+            )
+        else:
+            out, aux = apply_mlp(p["mlp"], h, cfg.mlp_kind, cfg.imc, rng), 0.0
     if cfg.post_norm:
         out = apply_norm(p["norm2_post"], out, cfg.norm_kind)
     return ws(x + out, "act_btd"), aux
 
 
+@jax.named_scope("block")
 def apply_block_full(
     p,
     x,  # (B, S, d)
@@ -147,21 +149,24 @@ def apply_block_full(
     cache = None
     if kind in ("attn", "local"):
         dims = attn_dims(cfg, kind)
-        q, k, v = attn_lib._project_qkv(p["mixer"], h, dims, positions,
-                                        cfg.imc, rng, site_prefix=kind)
-        if dims.window is not None and dims.window < h.shape[1]:
-            ctx = attn_lib.banded_attention(q, k, v, dims)
-        else:
-            ctx = attn_lib.flash_attention(q, k, v, dims)
-        b, s = h.shape[:2]
-        ctx = ctx.reshape(b, s, dims.n_heads * dims.head_dim)
-        out = attn_lib.linear(p["mixer"]["wo"], ctx, cfg.imc, rng,
-                              site=f"{kind}.wo")
+        with jax.named_scope("attn"):
+            q, k, v = attn_lib._project_qkv(p["mixer"], h, dims, positions,
+                                            cfg.imc, rng, site_prefix=kind)
+            if dims.window is not None and dims.window < h.shape[1]:
+                ctx = attn_lib.banded_attention(q, k, v, dims)
+            else:
+                ctx = attn_lib.flash_attention(q, k, v, dims)
+            b, s = h.shape[:2]
+            ctx = ctx.reshape(b, s, dims.n_heads * dims.head_dim)
+            out = attn_lib.linear(p["mixer"]["wo"], ctx, cfg.imc, rng,
+                                  site=f"{kind}.wo")
         if want_cache:
             cache = _pack_kv_cache(k, v, cache_len, dims.window, x.dtype,
                                    true_len)
     elif kind == "ssm":
-        out, state = ssm_lib.ssm_forward(p["mixer"], h, cfg, cfg.imc, rng)
+        with jax.named_scope("ssm"):
+            out, state = ssm_lib.ssm_forward(p["mixer"], h, cfg, cfg.imc,
+                                             rng)
         if want_cache:
             if true_len is not None:
                 # recurrent state integrates pad garbage; serve engines must
@@ -173,7 +178,9 @@ def apply_block_full(
                  if cfg.post_norm else out)
         return ws(x, "act_btd"), cache, 0.0  # mamba2: no MLP half
     elif kind == "rglru":
-        out, h_last = rg_lib.rglru_forward(p["mixer"], h, cfg, cfg.imc, rng)
+        with jax.named_scope("rglru"):
+            out, h_last = rg_lib.rglru_forward(p["mixer"], h, cfg, cfg.imc,
+                                               rng)
         if want_cache:
             if true_len is not None:
                 raise ValueError("bucketed (padded) prefill is not supported "
@@ -189,23 +196,29 @@ def apply_block_full(
     return x, cache, aux
 
 
+@jax.named_scope("block")
 def apply_block_decode(p, x, cfg: ArchConfig, kind: str, cache, pos, rng,
                        active=None):
     """One-token block. Returns (x, new_cache)."""
     h = apply_norm(p["norm1"], x, cfg.norm_kind)
     if kind in ("attn", "local"):
         dims = attn_dims(cfg, kind)
-        out, new_cache = attn_lib.attention_decode(
-            p["mixer"], h, cache, pos, dims, cfg.imc, rng, active=active,
-            site_prefix=kind,
-        )
+        with jax.named_scope("attn"):
+            out, new_cache = attn_lib.attention_decode(
+                p["mixer"], h, cache, pos, dims, cfg.imc, rng, active=active,
+                site_prefix=kind,
+            )
     elif kind == "ssm":
-        out, new_cache = ssm_lib.ssm_decode(p["mixer"], h, cache, cfg, cfg.imc, rng)
+        with jax.named_scope("ssm"):
+            out, new_cache = ssm_lib.ssm_decode(p["mixer"], h, cache, cfg,
+                                                cfg.imc, rng)
         x = x + (apply_norm(p["norm1_post"], out, cfg.norm_kind)
                  if cfg.post_norm else out)
         return x, new_cache
     elif kind == "rglru":
-        out, new_cache = rg_lib.rglru_decode(p["mixer"], h, cache, cfg, cfg.imc, rng)
+        with jax.named_scope("rglru"):
+            out, new_cache = rg_lib.rglru_decode(p["mixer"], h, cache, cfg,
+                                                 cfg.imc, rng)
     else:
         raise ValueError(kind)
     if cfg.post_norm:

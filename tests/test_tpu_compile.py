@@ -70,6 +70,37 @@ def test_paged_attention_compiles_for_v5e(one_chip, max_blocks):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_paged_attention_kernel_is_named_and_scoped(one_chip):
+    """A profile finds the kernel by its ``paged_attention`` scope, and the
+    benchmark's older reader by the pools' aliasing (outputs 1 and 2 alias
+    operands 7 and 8): the compiled custom call carries both."""
+    import re
+
+    max_blocks = 64
+    pool = _sds((SLOTS * max_blocks + 1, BS, HKV, HD), jnp.bfloat16, one_chip)
+    args = (
+        _sds((SLOTS, HKV, G, HD), jnp.bfloat16, one_chip),
+        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
+        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
+        pool, pool,
+        _sds((SLOTS, max_blocks), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.bool_, one_chip),
+    )
+
+    def step(q, kn, vn, pk, pv, bt, pos, active):
+        return paged_attention_decode(q, kn, vn, pk, pv, bt, pos, active,
+                                      scale=HD ** -0.5, use_pallas=True,
+                                      interpret=False)
+
+    text = jax.jit(step).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*/paged_attention/', calls[0])
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(7, \{\}\), "
+                     r"\{2\}: \(8, \{\}\)\}", calls[0])
+
+
 @pytest.mark.parametrize("hw_prng", [False, True], ids=["counter", "hardware"])
 @pytest.mark.parametrize("kernel", ["analytic", "bitserial"])
 def test_imc_kernel_with_noise_compiles_for_v5e(one_chip, monkeypatch,
