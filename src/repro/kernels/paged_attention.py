@@ -1,61 +1,72 @@
-"""Pallas paged-attention decode kernel: online softmax over the block table.
+"""Pallas paged-attention decode kernel: online softmax over a slot's live blocks.
 
 One decode step of attention for a batch of slots whose K/V live in a shared
 paged block pool (``repro.models.attention.init_paged_kv_cache`` layout:
 pools (num_blocks, block_size, Hkv, hd), per-slot block table (B, max_blocks),
-physical block 0 reserved as the GARBAGE block).  Instead of materializing the
-gathered ``pool[bt]`` copy (O(B * max_blocks * block) KV bytes per step) and
-running a dense softmax over it, the kernel walks the block table in-kernel:
-each grid step streams ONE physical block from the pool and folds it into an
-online-softmax accumulator, so the gathered copy never exists and the resident
-KV working set per step is O(1) in the context length.
+physical block 0 reserved as the GARBAGE block).  The kernel walks each
+slot's block table in-kernel and folds the blocks into an online-softmax
+accumulator, so the gathered ``pool[bt]`` copy never exists.
 
-Grid / accumulator layout (TPU mapping notes, in the style of
-``imc_mvm.py``):
+The walk (TPU mapping notes, in the style of ``imc_mvm.py``):
 
-  * grid = (B, max_blocks) with the logical-block axis j innermost: each
-    (b, j) step DMAs pool block ``bt[b, j]`` - the physical block id comes
-    from the scalar-prefetched block table via the BlockSpec index_map
-    (``pltpu.PrefetchScalarGridSpec``), the canonical paged-attention idiom.
-    The walk order is LOGICAL block order, so the output is invariant to the
-    physical block ids the allocator happened to hand out (preemption/resume
-    and defragmentation cannot perturb tokens).
-  * VMEM scratch carries the online-softmax state across the j steps of one
-    slot: running row-max ``m`` (Hkv, G), row-sum ``l`` (Hkv, G), weighted
-    accumulator ``acc`` (Hkv, G, hd) - the same m/l/corr recurrence as
-    ``_flash_fwd_impl`` (models/attention.py).  State is (re)initialized at
-    j == 0 and the normalized context ``acc / max(l, 1e-30)`` is flushed to
-    the output block at j == max_blocks - 1 (the output BlockSpec revisits
-    the same (1, Hkv, G, hd) block for every j, so only the final flush
-    survives).
-  * the new token's K/V is scattered into the tail block INSIDE the kernel:
-    the tail (b, j == pos[b] // bs) step overlays k_new/v_new onto row
-    ``pos[b] % bs`` of the streamed block in-register, and the pools are
-    aliased in-out (``input_output_aliases``) so each step writes its
-    (possibly overlaid) block back to a scalar-prefetched write destination.
+  * grid = (B,): one grid step per slot.  Inside it, a ``fori_loop`` runs
+    over the slot's COMPUTE blocks of ``n_fold`` pool blocks each
+    (``n_fold * bs`` positions).  Only LIVE blocks are walked: a slot's live
+    count is ``min(pos // bs + 1, max_blocks)``, derived in-kernel from the
+    scalar-prefetched ``pos``.  An inactive row walks its stale table the
+    same way, as the gather path does: a dynamic imc substrate couples the
+    rows of a batch through its statistics, so every row's context must be
+    the one the reference semantics give.  ``n_fold`` comes from the shapes
+    alone (``fold_blocks``: the pool block's VMEM bytes against
+    ``FOLD_VMEM_BYTES``, capped by ``max_blocks``).
+  * the pools stay where XLA puts them (``memory_space=pl.ANY``: HBM, or
+    VMEM when a layer's pool fits there).  A compute block is one DMA per
+    live pool block per pool, issued by hand in a scalar loop from the
+    scalar-prefetched (flattened) block table into one of two VMEM buffers:
+    the next compute block - of this slot, or the first of the next slot -
+    is fetched while this one is computed.  The loop keeps the kernel's
+    code, and the time to lower it into every program that holds it,
+    independent of ``n_fold``.  Blocks past the live count are never
+    fetched: the spare slots of a short last compute block keep whatever
+    the buffer held, and their positions are masked out of both the scores
+    and the values.  The walk order is LOGICAL block order, so the output
+    is invariant to the physical block ids the allocator handed out.
+  * the products keep the pool's (Hkv, hd) tile as it lands in VMEM: no
+    per-head MXU product of one query row and no transpose.  Scores are a
+    lane reduction of ``k * q`` per position and head, (P, Hkv, 1); the
+    context is ``sum_P p * v`` over the leading axis.  All of it runs on the
+    VPU in float32, one product per query group ``G``.
+  * VMEM scratch carries the online-softmax state across the compute blocks
+    of one slot: running max ``m`` (G, Hkv, 1), sum ``l`` (G, Hkv, 1) and
+    accumulator ``acc`` (G, Hkv, hd) - the same m/l/corr recurrence as
+    ``_flash_fwd_impl`` (models/attention.py).  The normalized context
+    ``acc / max(l, 1e-30)`` is written once per slot.
 
-Garbage-block-0 write contract: the per-step write destination ``wdest[b, j]``
-is the slot's physical tail block ONLY for the tail step of an active,
-in-range row; every other step - non-tail j, rows with ``active == False``
-(a retired slot's stale table may point at blocks the allocator already
-reused), and OVERRUN rows (``pos >= max_blocks * bs``, which previously
-clobbered the slot's last live block) - is routed to physical block 0, whose
-content is garbage by pool contract.  ``write_routing`` below is the single
-source of truth for this routing; the gather escape-hatch path in
-``models/attention.py`` and the ``ref.py`` oracle share it.
+The new token's K/V is written ONCE per slot: one (Hkv, hd) DMA per pool from
+the token's VMEM block to row ``pos % bs`` of the scalar-prefetched write
+destination.  An active, in-range slot attends it from an overlay: once the
+tail compute block has landed in VMEM, the new row is stored over its stale
+copy there.  The pools are aliased in-out (``input_output_aliases``), so
+nothing else of either pool is written.
+
+Garbage-block-0 write contract: the write destination ``dest[b]`` is the
+slot's physical tail block ONLY for an active, in-range row; rows with
+``active == False`` (a retired slot's stale table may point at blocks the
+allocator already reused) and OVERRUN rows (``pos >= max_blocks * bs``)
+write to physical block 0, whose content is garbage by pool contract, and
+take no overlay: an inactive row attends the STALE tail value, as the gather
+path does.  ``write_routing`` below is the single source of truth for this
+routing; the gather escape-hatch path in ``models/attention.py`` and the
+``ref.py`` oracle share it.
 
 CPU / interpret story (the ``kernels/prng.py`` precedent): on non-TPU
 backends ``paged_attention_decode`` dispatches to a pure-JAX fallback
-(`lax.scan` over logical blocks) implementing the identical streamed
-recurrence - bit-reproducible math, no Pallas interpreter overhead inside the
-serve decode scan.  The Pallas kernel itself runs under ``interpret=True``
-only in the dedicated equivalence tests (tests/test_paged_attention.py),
-which check it against the fallback and against the gather-path oracle
-``ref.paged_attention_ref``.  On real TPU the aliased in-out pool revisits
-physical block 0 from multiple grid steps; the only step whose write targets
-a block read by a LATER step is the tail step of the owning slot itself
-(slots own disjoint blocks), which reads and writes within the same step, so
-the sequential grid semantics are preserved.
+(``lax.scan`` over compute blocks) implementing the identical recurrence -
+the reference semantics, with no Pallas interpreter overhead inside the serve
+decode scan.  The Pallas kernel itself runs under ``interpret=True`` in the
+equivalence tests (tests/test_paged_attention.py), which check it against
+the fallback and against the gather-path oracle ``ref.paged_attention_ref``;
+the same file checks the compiled kernel against the oracle on a TPU.
 """
 from __future__ import annotations
 
@@ -68,10 +79,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# VMEM bytes of one compute block of one pool (one of its two buffers)
+FOLD_VMEM_BYTES = 512 * 1024
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def fold_blocks(bs: int, hkv: int, hd: int, itemsize: int,
+                max_blocks: int) -> int:
+    """Pool blocks per compute block: the largest power of two whose DMA
+    buffer (``bs`` slabs of (Hkv, hd), padded to 8 sublanes and 128 lanes)
+    stays within ``FOLD_VMEM_BYTES``, and no more than the table needs."""
+    tile = -(-hkv // 8) * 8 * (-(-hd // 128) * 128)
+    n = max(1, FOLD_VMEM_BYTES // (bs * tile * itemsize))
+    n = 1 << (n.bit_length() - 1)
+    return min(n, 1 << (max_blocks - 1).bit_length())
+
+
+def _lane_window(hd: int, interpret: bool) -> int:
+    """Lanes each pool DMA moves: ``hd`` rounded up to whole 128-lane tiles
+    on the chip.  A pool whose head dim is under 128 (musicgen's 64) lies in
+    memory with each (Hkv, hd) slab padded to 128 lanes, and Mosaic slices only
+    whole lane tiles, so a DMA moves the padded tile; the compute reads the
+    first ``hd`` lanes.  The interpreter holds the logical array."""
+    return hd if interpret else -(-hd // 128) * 128
 
 
 # ---------------------------------------------------------------------------
@@ -99,52 +132,58 @@ def write_routing(bt: jax.Array, pos_b: jax.Array, block_size: int,
 
 
 # ---------------------------------------------------------------------------
-# pure-JAX fallback: the same streamed recurrence, lax.scan over blocks
+# pure-JAX fallback: the same recurrence, lax.scan over compute blocks
 # ---------------------------------------------------------------------------
 
 
 def _decode_jax(q, k_new, v_new, pk, pv, bt, pos_b, dest, off,
-                scale: float, softcap: Optional[float]):
-    """Streamed online-softmax walk over logical blocks (CPU serving path).
+                scale: float, softcap: Optional[float], n_fold: int):
+    """Online-softmax walk over compute blocks of ``n_fold`` logical blocks
+    (CPU serving path).
 
-    Scatters the new K/V first (same pool state as the kernel's in-kernel
-    overlay + aliased write-back), then folds one (B, bs, Hkv, hd) block per
-    scan step into the m/l/acc recurrence.  The gathered ``pool[bt]`` copy is
-    never materialized.
+    Scatters the new K/V first (same pool state as the kernel's single
+    write), then folds one (B, n_fold * bs, Hkv, hd) block per scan step
+    into the m/l/acc recurrence, with the kernel's products and masks.  The
+    gathered ``pool[bt]`` copy is never materialized.  Every row walks its
+    table through its tail block: an inactive row attends its stale table,
+    as the gather path and the kernel do.
     """
     b, max_blocks = bt.shape
     bs = pk.shape[1]
     pk = pk.at[dest, off].set(k_new)
     pv = pv.at[dest, off].set(v_new)
-    qf = q.astype(jnp.float32)
     hkv, g, hd = q.shape[1], q.shape[2], q.shape[3]
+    qf = q.astype(jnp.float32).transpose(0, 2, 1, 3)  # (B, G, Hkv, hd)
+    n_cb = -(-max_blocks // n_fold)
+    bt_pad = jnp.pad(bt, ((0, 0), (0, n_cb * n_fold - max_blocks)))
+    p_len = n_fold * bs
 
-    def blk_step(carry, j):
+    def blk_step(carry, c):
         m, l, acc = carry
-        phys = bt[:, j]
-        k_blk = pk[phys].astype(jnp.float32)  # (B, bs, Hkv, hd)
-        v_blk = pv[phys].astype(jnp.float32)
-        s = jnp.einsum("bhgd,bkhd->bhgk", qf, k_blk) * scale
+        phys = jax.lax.dynamic_slice_in_dim(bt_pad, c * n_fold, n_fold, 1)
+        k_blk = pk[phys].astype(jnp.float32).reshape(b, p_len, hkv, hd)
+        v_blk = pv[phys].astype(jnp.float32).reshape(b, p_len, hkv, hd)
+        s = jnp.sum(k_blk[:, None] * qf[:, :, None], axis=-1,
+                    keepdims=True) * scale  # (B, G, P, Hkv, 1)
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * bs + jnp.arange(bs)
-        valid = k_pos[None, :] <= pos_b[:, None]
-        s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
+        k_pos = c * p_len + jnp.arange(p_len)
+        valid = ((k_pos[None, :] <= pos_b[:, None])
+                 & (k_pos[None, :] < max_blocks * bs))
+        s = jnp.where(valid[:, None, :, None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2))
+        p = jnp.exp(s - m_new[:, :, None])
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        pv_blk = jnp.einsum("bhgk,bkhd->bhgd", p, v_blk)
-        acc_new = acc * corr[..., None] + pv_blk
+        l_new = l * corr + jnp.sum(p, axis=2)
+        acc_new = acc * corr + jnp.sum(p * v_blk[:, None], axis=2)
         return (m_new, l_new, acc_new), None
 
-    m0 = jnp.full((b, hkv, g), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, hkv, g), jnp.float32)
-    a0 = jnp.zeros((b, hkv, g, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(blk_step, (m0, l0, a0),
-                                  jnp.arange(max_blocks))
-    ctx = acc / jnp.maximum(l[..., None], 1e-30)
-    return ctx, pk, pv
+    m0 = jnp.full((b, g, hkv, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((b, g, hkv, 1), jnp.float32)
+    a0 = jnp.zeros((b, g, hkv, hd), jnp.float32)
+    (m, l, acc), _ = jax.lax.scan(blk_step, (m0, l0, a0), jnp.arange(n_cb))
+    ctx = acc / jnp.maximum(l, 1e-30)
+    return ctx.transpose(0, 2, 1, 3), pk, pv
 
 
 # ---------------------------------------------------------------------------
@@ -152,110 +191,201 @@ def _decode_jax(q, k_new, v_new, pk, pv, bt, pos_b, dest, off,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(bt_ref, wdest_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref,
-                  pk_ref, pv_ref, ctx_ref, opk_ref, opv_ref,
-                  m_scr, l_scr, acc_scr, *, bs: int, scale: float,
+def _paged_kernel(bt_ref, dest_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref,
+                  pk_hbm, pv_hbm, ctx_ref, opk_hbm, opv_hbm,
+                  kbuf, vbuf, sems, wsems, next_buf, m_scr, l_scr, acc_scr,
+                  *, n_fold: int, bs: int, max_blocks: int, scale: float,
                   softcap: Optional[float]):
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
+    n_rows = pl.num_programs(0)
+    p_len = n_fold * bs
+    g_count, hkv, hd = q_ref.shape[1:]
+    # every DMA moves whole lane tiles (``_lane_window``)
+    lanes = pl.ds(0, kbuf.shape[-1])
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def live(row):
+        """Blocks ``row`` walks: through its tail block, within the table
+        (at least one: a row's position 0 lies in its first block)."""
+        return jnp.minimum(pos_ref[row] // bs + 1, max_blocks)
+
+    def count(row, c):
+        """Pool blocks in compute block ``c`` of ``row``: ``n_fold``, fewer
+        in a short last one."""
+        return jnp.minimum(n_fold, live(row) - c * n_fold)
+
+    def copies(blk, i, buf):
+        """Pool block ``blk`` into slot ``i`` of buffer ``buf``, K and V."""
+        return (pltpu.make_async_copy(pk_hbm.at[blk, :, :, lanes],
+                                      kbuf.at[buf, i], sems.at[0, buf]),
+                pltpu.make_async_copy(pv_hbm.at[blk, :, :, lanes],
+                                      vbuf.at[buf, i], sems.at[1, buf]))
+
+    def start(row, c, buf):
+        """Fetch the live blocks of compute block ``c`` of ``row`` into
+        buffer ``buf``."""
+        first = row * max_blocks + c * n_fold
+
+        def issue(i, carry):
+            for cp in copies(bt_ref[first + i], i, buf):
+                cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, count(row, c), issue, 0)
+
+    def wait(row, c, buf):
+        def one(i, carry):
+            for cp in copies(0, i, buf):  # a wait reads only the size
+                cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, count(row, c), one, 0)
 
     pos = pos_ref[b]
-    k_blk = pk_ref[0]  # (bs, Hkv, hd) - the streamed physical block
-    v_blk = pv_ref[0]
-    # in-register overlay of the new token onto the tail block's row -
-    # gated on the write mask: an inactive row's write goes to garbage, so
-    # its tail lane must keep attending the STALE pool value (gather-path
-    # semantics; the row's output is discarded by the engine anyway)
-    # (masks are built at full rank: Mosaic cannot reshape an i1 vector)
-    row = jax.lax.broadcasted_iota(jnp.int32, k_blk.shape, 0)
-    sel = (row == pos % bs) & (j == pos // bs) & (act_ref[b] != 0)
-    k_blk = jnp.where(sel, kn_ref[0][None], k_blk)
-    v_blk = jnp.where(sel, vn_ref[0][None], v_blk)
-    # aliased write-back: the tail step persists the overlay into the slot's
-    # tail block; every other step's destination is garbage block 0
-    opk_ref[0] = k_blk
-    opv_ref[0] = v_blk
+    n_live = live(b)
+    n_cb = (n_live + n_fold - 1) // n_fold
 
-    qf = q_ref[0].astype(jnp.float32)  # (Hkv, G, hd)
-    s = jnp.einsum("hgd,khd->hgk", qf, k_blk.astype(jnp.float32)) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(k_pos <= pos, s, NEG_INF)
-    m = m_scr[...]
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[..., None])
-    corr = jnp.exp(m - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[..., None] + jnp.einsum(
-        "hgk,khd->hgd", p, v_blk.astype(jnp.float32))
-    m_scr[...] = m_new
+    # every row walks at least one block, so the previous row has fetched
+    # this row's first compute block into ``next_buf``
+    @pl.when(b == 0)
+    def _first_row():
+        next_buf[0] = 0
+        start(b, 0, 0)
 
-    @pl.when(j == n_blocks - 1)
-    def _flush():
-        ctx_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...][..., None], 1e-30)
+    buf0 = next_buf[0]
+
+    # the single write of the new token (dest is garbage block 0 unless the
+    # row is active and in range)
+    off = pos % bs
+    writes = (
+        pltpu.make_async_copy(kn_ref.at[0],
+                              opk_hbm.at[dest_ref[b], off, :, lanes],
+                              wsems.at[0]),
+        pltpu.make_async_copy(vn_ref.at[0],
+                              opv_hbm.at[dest_ref[b], off, :, lanes],
+                              wsems.at[1]),
+    )
+    for cp in writes:
+        cp.start()
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    tail = pos // bs
+    # only a row whose write lands in its tail block attends the new token
+    tail_c = jnp.where((tail < max_blocks) & (act_ref[b] != 0),
+                       tail // n_fold, -1)
+    nxt_row = jnp.minimum(b + 1, n_rows - 1)
+
+    def body(c, buf):
+        other = 1 - buf
+
+        @pl.when(c + 1 < n_cb)
+        def _prefetch_same_row():
+            start(b, c + 1, other)
+
+        @pl.when((c + 1 == n_cb) & (b + 1 < n_rows))
+        def _prefetch_next_row():
+            start(nxt_row, 0, other)
+
+        wait(b, c, buf)
+
+        @pl.when(c == tail_c)
+        def _overlay():
+            kbuf[buf, tail % n_fold, off] = kn_ref[0]
+            vbuf[buf, tail % n_fold, off] = vn_ref[0]
+
+        k = kbuf[buf, :, :, :, :hd].astype(jnp.float32).reshape(
+            p_len, hkv, hd)
+        v = vbuf[buf, :, :, :, :hd].astype(jnp.float32).reshape(
+            p_len, hkv, hd)
+        k_pos = c * p_len + jax.lax.broadcasted_iota(
+            jnp.int32, (p_len, hkv, 1), 0)
+        valid = (k_pos <= pos) & (k_pos < max_blocks * bs)
+        # the spare slots of a short last compute block hold stale VMEM
+        # (the mask is built at full rank: Mosaic cannot broadcast an i1
+        # vector across lanes)
+        v_pos = c * p_len + jax.lax.broadcasted_iota(
+            jnp.int32, (p_len, hkv, hd), 0)
+        v = jnp.where(v_pos < n_live * bs, v, 0.0)
+        for g in range(g_count):
+            qg = q_ref[0, g].astype(jnp.float32)  # (Hkv, hd)
+            s = jnp.sum(k * qg[None], axis=-1, keepdims=True) * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(valid, s, NEG_INF)  # (P, Hkv, 1)
+            m = m_scr[g]
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])
+            corr = jnp.exp(m - m_new)
+            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=0)
+            acc_scr[g] = acc_scr[g] * corr + jnp.sum(p * v, axis=0)
+            m_scr[g] = m_new
+        return other
+
+    next_buf[0] = jax.lax.fori_loop(0, n_cb, body, buf0)
+    ctx_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+    for cp in writes:
+        cp.wait()
 
 
-def _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, dest, off, act,
-                   scale: float, softcap: Optional[float], interpret: bool):
-    """pallas_call wrapper: scalar-prefetched block table + write routing."""
+def _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, act, dest,
+                   scale: float, softcap: Optional[float], n_fold: int,
+                   interpret: bool):
+    """pallas_call wrapper: scalar-prefetched block table, write
+    destination, positions and activity."""
     b, max_blocks = bt.shape
     bs, hkv, hd = pk.shape[1], pk.shape[2], pk.shape[3]
     g = q.shape[2]
-    # per-(b, j) write destination: garbage block 0 everywhere except the
-    # (in-range, active) tail step, which gets the slot's real tail block
-    wdest = jnp.zeros((b, max_blocks), jnp.int32).at[
-        jnp.arange(b), jnp.clip(pos_b // bs, 0, max_blocks - 1)].set(dest)
+    lanes = _lane_window(hd, interpret)
+    qg = q.transpose(0, 2, 1, 3)  # (B, G, Hkv, hd): one (Hkv, hd) tile a group
+    pad = ((0, 0), (0, 0), (0, lanes - hd))
+    k_new, v_new = jnp.pad(k_new, pad), jnp.pad(v_new, pad)
+    row = lambda bb, *_: (bb, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # bt, wdest, pos, active
-        grid=(b, max_blocks),
+        num_scalar_prefetch=4,  # bt (flattened), dest, pos, active
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hkv, g, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bb, 0, 0)),
-            pl.BlockSpec((1, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bb, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bt_[bb, jj], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bt_[bb, jj], 0, 0, 0)),
+            pl.BlockSpec((1, g, hkv, hd), lambda bb, *_: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, lanes), row),
+            pl.BlockSpec((1, hkv, lanes), row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, hkv, g, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (wd[bb, jj], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, hd),
-                         lambda bb, jj, bt_, wd, ps, ac: (wd[bb, jj], 0, 0, 0)),
+            pl.BlockSpec((1, g, hkv, hd), lambda bb, *_: (bb, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hkv, g), jnp.float32),
-            pltpu.VMEM((hkv, g), jnp.float32),
-            pltpu.VMEM((hkv, g, hd), jnp.float32),
+            pltpu.VMEM((2, n_fold, bs, hkv, lanes), pk.dtype),
+            pltpu.VMEM((2, n_fold, bs, hkv, lanes), pv.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, hd), jnp.float32),
         ],
     )
     ctx, opk, opv = pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, scale=scale, softcap=softcap),
+        functools.partial(_paged_kernel, n_fold=n_fold, bs=bs,
+                          max_blocks=max_blocks, scale=scale,
+                          softcap=softcap),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, g, hkv, hd), jnp.float32),
             jax.ShapeDtypeStruct(pk.shape, pk.dtype),
             jax.ShapeDtypeStruct(pv.shape, pv.dtype),
         ],
         # operand indices count the 4 scalar-prefetch args: pk = 7, pv = 8
         input_output_aliases={7: 1, 8: 2},
+        # the walk carries its DMA buffers from one row to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(bt, wdest, pos_b, act, q, k_new, v_new, pk, pv)
-    return ctx, opk, opv
+    )(bt.reshape(-1), dest, pos_b, act, qg, k_new, v_new, pk, pv)
+    return ctx.transpose(0, 2, 1, 3), opk, opv
 
 
 # ---------------------------------------------------------------------------
@@ -279,31 +409,34 @@ def paged_attention_decode(
     use_pallas: Optional[bool] = None,
     interpret: Optional[bool] = None,
 ):
-    """Fused scatter + block-table walk + online-softmax decode attention.
+    """Fused single write + live-block walk + online-softmax decode attention.
 
     Returns ``(ctx (B, Hkv, G, hd) f32, pk, pv)`` with the new token's K/V
-    scattered into the pools per the garbage-block-0 contract.  Dispatch
-    mirrors ``kernels/prng.py``: the Pallas kernel on TPU, the pure-JAX
-    streamed fallback (identical math) elsewhere; ``use_pallas``/``interpret``
-    force either path for the interpret-mode equivalence tests.  Both paths
-    run under the ``paged_attention`` named scope, which a profile's op
-    names carry.
+    written into the pools per the garbage-block-0 contract; an inactive
+    row attends its stale table (the engine discards its context).
+    Dispatch mirrors ``kernels/prng.py``: the Pallas kernel on TPU, the
+    pure-JAX fallback (identical math) elsewhere; ``use_pallas``/``interpret``
+    force either path for the equivalence tests.  Both walk compute blocks
+    of ``fold_blocks`` pool blocks, from the shapes.  Both paths run under
+    the ``paged_attention`` named scope, which a profile's op names carry.
     """
     pos_b = pos_b.astype(jnp.int32)
-    # cast ONCE to the pool dtype before both the scatter and the overlay so
+    # cast ONCE to the pool dtype before both the write and the overlay so
     # the kernel attends over exactly the value the pool ends up holding
     # (bit-compat with the gather path, which scatters then re-reads)
     k_new = k_new.astype(pk.dtype)
     v_new = v_new.astype(pv.dtype)
-    dest, off = write_routing(bt, pos_b, pk.shape[1], active)
+    bs, hkv, hd = pk.shape[1], pk.shape[2], pk.shape[3]
+    n_fold = fold_blocks(bs, hkv, hd, pk.dtype.itemsize, bt.shape[1])
+    dest, off = write_routing(bt, pos_b, bs, active)
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
         return _decode_jax(q, k_new, v_new, pk, pv, bt, pos_b, dest, off,
-                           scale, softcap)
+                           scale, softcap, n_fold)
     if interpret is None:
         interpret = _interpret_default()
     act = (jnp.ones(pos_b.shape, jnp.int32) if active is None
            else active.astype(jnp.int32))
-    return _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, dest, off, act,
-                          scale, softcap, interpret)
+    return _decode_pallas(q, k_new, v_new, pk, pv, bt, pos_b, act, dest,
+                          scale, softcap, n_fold, interpret)

@@ -132,11 +132,12 @@ a group), ``engine.prefill`` (its arguments and the prefill dispatch; args
 (the sync on its first tokens); ``engine.decode`` (one ``decode_chunk`` call)
 holds ``engine.grow`` (block growth and its extend dispatch),
 ``engine.dispatch`` (arguments and the chunk dispatch; args ``steps``,
-``active``), ``engine.sync`` (the token block's transfer) and
-``engine.deliver`` (token bookkeeping and retirement).  The device programs
-carry named scopes (``embed``, ``block``, ``attn``, ``mlp``, ``head``,
-``sample``, ``paged_attention``, each matmul's site, ``imc``) in their op
-names.
+``active``, ``live_blocks``: the blocks the paged walk reads for the
+active slots in the chunk, summed into ``kv_blocks_walked``), ``engine.sync``
+(the token block's transfer) and ``engine.deliver`` (token bookkeeping and
+retirement).  The device programs carry named scopes (``embed``, ``block``,
+``attn``, ``mlp``, ``head``, ``sample``, ``paged_attention``, each matmul's
+site, ``imc``) in their op names.
 """
 from __future__ import annotations
 
@@ -539,6 +540,12 @@ class Engine:
         self.host_transfer_bytes = 0
         self.prefill_calls = 0
         self.prefill_rows = 0
+        # paged walk engagement: blocks the decode kernel walks for active
+        # slots (each one's live blocks, through its tail block, at each
+        # step; an idle slot also walks its stale table, uncounted) against
+        # the blocks their tables hold (max_blocks per active slot and step)
+        self.kv_blocks_walked = 0
+        self.kv_blocks_tabled = 0
         # robustness counters
         self.failed_requests = 0
         self.decode_failures = 0
@@ -1680,8 +1687,9 @@ class Engine:
             self._ensure_blocks(n_steps)
         if self.active == 0:
             return np.zeros((self.batch_slots, 0), np.int32)
+        live_blocks = self._walked_blocks(n_steps)
         with TraceAnnotation("engine.dispatch", steps=n_steps,
-                             active=self.active):
+                             active=self.active, live_blocks=live_blocks):
             shadow = (self._drift is not None and self.active > 0
                       and not self._drift_paused()
                       and self._drift.take_sample())
@@ -1725,11 +1733,23 @@ class Engine:
         with TraceAnnotation("engine.sync"):
             block = np.asarray(toks)  # the one host transfer per chunk
         with TraceAnnotation("engine.deliver"):
-            self._deliver(block, n_steps, int(active_host.sum()), shadow)
+            self._deliver(block, n_steps, int(active_host.sum()), shadow,
+                          live_blocks)
         return block
 
+    def _walked_blocks(self, n_steps: int) -> int:
+        """Blocks the paged decode walk reads in a chunk of ``n_steps``,
+        from the host positions: at each step, every active slot's blocks
+        through its tail block (``kernels.paged_attention``'s live count)."""
+        if not self.has_paged:
+            return 0
+        pos = np.array([p for p, r in zip(self._slot_pos, self.slots)
+                        if r is not None], np.int64)
+        steps = pos[:, None] + np.arange(n_steps)[None, :]
+        return int(np.minimum(steps // self.block + 1, self.max_blocks).sum())
+
     def _deliver(self, block: np.ndarray, n_steps: int, n_active: int,
-                 shadow: bool) -> None:
+                 shadow: bool, live_blocks: int) -> None:
         """Account a finished chunk and hand its tokens to the requests,
         retiring those that are done."""
         if self.meter is not None:
@@ -1740,6 +1760,9 @@ class Engine:
         self.decode_calls += 1
         self.decode_steps += n_steps
         self.host_transfer_bytes += block.nbytes
+        self.kv_blocks_walked += live_blocks
+        if self.has_paged:
+            self.kv_blocks_tabled += n_active * n_steps * self.max_blocks
         # pool-utilization sample: tokens live in active caches vs the token
         # capacity of the blocks currently allocated (lazy vs reserve signal)
         if self.has_paged and self.alloc.used_count:

@@ -401,10 +401,10 @@ def _attention_decode_paged(params, x, cache, pos_b, dims: AttnDims, imc, rng,
     the block table.
 
     Default path (``dims.paged_kernel``): the fused kernel in
-    ``repro.kernels.paged_attention`` walks the block table in-kernel,
-    streaming one physical block per step into an online-softmax accumulator
-    and scattering the new token inside the same kernel - the gathered
-    ``pool[bt]`` copy never exists.  Escape hatch (``cfg.decode_attn =
+    ``repro.kernels.paged_attention`` walks each slot's live blocks in-kernel,
+    several per step, into an online-softmax accumulator and writes the new
+    token once inside the same kernel - the gathered ``pool[bt]`` copy never
+    exists.  Escape hatch (``cfg.decode_attn =
     "gather"``): scatter, then materialize the gathered view and run a
     full-row softmax (the reference math, kept selectable for debugging).
 

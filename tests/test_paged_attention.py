@@ -1,10 +1,15 @@
 """Paged-attention decode kernel equivalence suite.
 
-Unit level: the streamed online-softmax kernel (pure-JAX fallback AND the
+Unit level: the live-block online-softmax walk (pure-JAX fallback AND the
 Pallas kernel under interpret mode) against the gather-path oracle
 ``ref.paged_attention_ref`` - per-step ctx allclose, pool updates bit-exact
 outside the garbage block, the garbage-block-0 write-routing contract
-(inactive rows, OVERRUN rows), and block-boundary crossing.
+(inactive rows, OVERRUN rows), block-boundary crossing, and, for several
+compute-block folds ``n_fold`` (set through ``FOLD_VMEM_BYTES``), positions at
+compute-block edges, live counts of 1 and of ``max_blocks``, and slots of very
+different lengths in one batch.  Every path gives an inactive row the context
+of its stale table.  On a TPU the compiled kernel is checked against the
+oracle at both served geometries.
 
 Serve level: greedy token streams from the fused-kernel engine
 (``decode_attn="kernel"``, the default) are bit-identical to the gather
@@ -17,6 +22,8 @@ Plus the two satellite pins that ride along this PR: the
 ``attention_forward`` window >= S dispatch equivalence and the
 ``slo_summary`` zero-elapsed goodput guard.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +32,9 @@ import pytest
 from repro import configs
 from repro.core.imc_linear import IMCConfig
 from repro.core.substrate import as_substrate, calibrate_model
-from repro.kernels.paged_attention import paged_attention_decode, write_routing
+from repro.kernels import paged_attention as pa
+from repro.kernels.paged_attention import (fold_blocks, paged_attention_decode,
+                                          write_routing)
 from repro.kernels.ref import paged_attention_ref
 from repro.launch.serve import Engine, Request, serve
 from repro.models import init_params
@@ -89,7 +98,7 @@ def test_kernel_matches_gather_oracle(softcap):
         _all_paths(state, active, softcap)
     np.testing.assert_allclose(ctx_f, ctx_r, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(ctx_p, ctx_r, atol=1e-5, rtol=1e-5)
-    # the streamed recurrence is the same math in both implementations
+    # the walk is the same math in both implementations
     np.testing.assert_allclose(ctx_p, ctx_f, atol=1e-6, rtol=1e-6)
     for got_k, got_v in ((pk_f, pv_f), (pk_p, pv_p)):
         assert jnp.array_equal(got_k[1:], pk_r[1:])
@@ -121,6 +130,125 @@ def test_kernel_matches_oracle_per_step_across_block_boundary():
         np.testing.assert_allclose(ctx_k, ctx_o, atol=1e-5, rtol=1e-5)
         assert jnp.array_equal(pk_k[1:], pk_o[1:]), pos
         assert jnp.array_equal(pv_k[1:], pv_o[1:]), pos
+
+
+# ---------------------------------------------------------------------------
+# the live-block walk at several compute-block folds
+# ---------------------------------------------------------------------------
+
+FOLDS = [1, 2, 4, 8]
+
+
+def _walk_state(n_fold, pos, active, bs=4, hkv=2, g=2, hd=8, seed=9):
+    """A batch whose every row owns a full table of distinct blocks (stale
+    entries past ``pos`` included), ``max_blocks = 2 * n_fold + 3``."""
+    rng = np.random.default_rng(seed)
+    b, mb = len(pos), 2 * n_fold + 3
+    nb = b * mb + 1
+    f32 = jnp.float32
+    q = jnp.asarray(rng.normal(size=(b, hkv, g, hd)), f32)
+    kn = jnp.asarray(rng.normal(size=(b, hkv, hd)), f32)
+    vn = jnp.asarray(rng.normal(size=(b, hkv, hd)), f32)
+    pk = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), f32)
+    pv = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), f32)
+    bt = jnp.asarray(1 + rng.permutation(nb - 1).reshape(b, mb), jnp.int32)
+    return (q, kn, vn, pk, pv, bt, jnp.asarray(pos, jnp.int32),
+            jnp.asarray(active))
+
+
+def _check_walk(monkeypatch, n_fold, pos, active):
+    q, kn, vn, pk, pv, bt, pos_b, act = _walk_state(n_fold, pos, active)
+    # one pool block of the state takes 16 KiB of VMEM (4 rows of an
+    # (8, 128) float32 tile), so this budget folds ``n_fold`` of them
+    monkeypatch.setattr(pa, "FOLD_VMEM_BYTES", n_fold * 16 * 1024)
+    assert fold_blocks(4, 2, 8, 4, bt.shape[1]) == n_fold
+    ctx_r, pk_r, pv_r = paged_attention_ref(q, kn, vn, pk, pv, bt, pos_b,
+                                            act, scale=SCALE)
+    outs = [paged_attention_decode(q, kn, vn, pk, pv, bt, pos_b, act,
+                                   scale=SCALE, use_pallas=pallas,
+                                   interpret=True)
+            for pallas in (False, True)]
+    (ctx_f, _, _), (ctx_p, _, _) = outs
+    np.testing.assert_allclose(ctx_f, ctx_r, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(ctx_p, ctx_r, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(ctx_p, ctx_f, atol=1e-6, rtol=1e-6)
+    for _, pk2, pv2 in outs:
+        assert jnp.array_equal(pk2[1:], pk_r[1:])
+        assert jnp.array_equal(pv2[1:], pv_r[1:])
+
+
+@pytest.mark.parametrize("n_fold", FOLDS)
+def test_walk_at_compute_block_edges(monkeypatch, n_fold):
+    """Positions one before, at and one after the end of the first and the
+    second compute block (``k * n_fold * bs - 1``, ``k * n_fold * bs``,
+    ``k * n_fold * bs + 1``): the tail lands in the last or the first block
+    of a compute block, and the walk stops there."""
+    edge = n_fold * 4
+    pos = [edge - 1, edge, edge + 1, 2 * edge - 1, 2 * edge, 2 * edge + 1]
+    _check_walk(monkeypatch, n_fold, pos, [True] * len(pos))
+
+
+@pytest.mark.parametrize("n_fold", FOLDS)
+def test_walk_mixed_batch(monkeypatch, n_fold):
+    """One batch of very different rows: a live count of 1 (pos 0) and of
+    ``max_blocks`` (the last position), a short and a long slot, an
+    inactive row whose stale table runs past its ``pos``, an inactive row at
+    ``pos`` 0, and an OVERRUN row (its write to garbage, its walk clamped
+    to the table)."""
+    cap = (2 * n_fold + 3) * 4  # max_blocks * bs
+    pos = [0, cap - 1, 3, cap - 6, 5, 0, cap + 2]
+    active = [True, True, True, True, False, False, True]
+    _check_walk(monkeypatch, n_fold, pos, active)
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="the compiled kernel runs only on a TPU")
+@pytest.mark.parametrize("hkv,g,hd", [(24, 1, 64), (8, 2, 128)],
+                         ids=["musicgen", "internvl2"])
+def test_compiled_kernel_matches_oracle_on_the_chip(hkv, g, hd):
+    """The kernel as the chip runs it (``interpret=False``: whole-lane-tile
+    DMAs over the pool as it lies in HBM, at the fold the shapes give)
+    against the gather oracle, at both served geometries: a live count of 1
+    and of ``max_blocks``, a short last compute block, an inactive row and
+    an OVERRUN row.  Context to float32 tolerance, pools bit-exact outside
+    garbage block 0."""
+    rng = np.random.default_rng(11)
+    bs, mb = 8, 40
+    pos = [0, mb * bs - 1, 37, 150, 200, mb * bs + 3]
+    active = [True, True, True, True, False, True]
+    b, nb = len(pos), len(pos) * mb + 1
+    bf = jnp.bfloat16
+    q = jnp.asarray(rng.normal(size=(b, hkv, g, hd)), bf)
+    kn = jnp.asarray(rng.normal(size=(b, hkv, hd)), bf)
+    vn = jnp.asarray(rng.normal(size=(b, hkv, hd)), bf)
+    pk = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), bf)
+    pv = jnp.asarray(rng.normal(size=(nb, bs, hkv, hd)), bf)
+    bt = jnp.asarray(1 + rng.permutation(nb - 1).reshape(b, mb), jnp.int32)
+    args = (q, kn, vn, pk, pv, bt, jnp.asarray(pos, jnp.int32),
+            jnp.asarray(active))
+    scale = hd ** -0.5
+    with jax.default_matmul_precision("highest"):
+        ctx_r, pk_r, pv_r = jax.jit(functools.partial(
+            paged_attention_ref, scale=scale))(*args)
+    ctx, pk2, pv2 = jax.jit(functools.partial(
+        paged_attention_decode, scale=scale, use_pallas=True,
+        interpret=False))(*args)
+    np.testing.assert_allclose(np.asarray(ctx), np.asarray(ctx_r),
+                               atol=1e-4, rtol=1e-4)
+    assert jnp.array_equal(pk2[1:], pk_r[1:])
+    assert jnp.array_equal(pv2[1:], pv_r[1:])
+
+
+def test_fold_comes_from_the_shapes():
+    """``n_fold`` is a power of two set by the block's VMEM footprint, never
+    more than the table needs, at least one."""
+    for bs, hkv, hd, mb in ((8, 24, 64, 250), (8, 8, 128, 288),
+                            (4, 2, 16, 6), (8, 24, 64, 1)):
+        n = fold_blocks(bs, hkv, hd, 2, mb)
+        assert n >= 1 and n & (n - 1) == 0
+        assert n < 2 * mb
+    # a wider block folds fewer blocks a step
+    assert fold_blocks(8, 24, 64, 2, 4096) < fold_blocks(8, 8, 128, 2, 4096)
 
 
 # ---------------------------------------------------------------------------
@@ -169,9 +297,9 @@ def test_overrun_write_does_not_clobber_tail_block(path):
 
 
 def test_inactive_row_writes_garbage_and_attends_stale():
-    """An inactive row's write must land in garbage block 0, and its ctx must
-    equal the gather path's (which attends the STALE tail value, since the
-    new K/V never reached the row's tail block)."""
+    """An inactive row's write must land in garbage block 0 on every path,
+    leaving its stale tail in place, and every path must attend the STALE
+    tail value (the new K/V never reached the row's tail block)."""
     state = _paged_state(seed=6, b=2, mb=3, bs=4, nb=8, hkv=2, g=1, hd=8,
                          pos=(5, 6))
     active = jnp.asarray([True, False])
@@ -181,6 +309,13 @@ def test_inactive_row_writes_garbage_and_attends_stale():
     tail1 = int(bt[1, pos_b[1] // 4])
     for got in (pk_r, pk_f, pk_p):
         assert jnp.array_equal(got[tail1], pk[tail1])  # stale tail kept
+    # the oracle attends the stale value: an active row whose new K/V IS
+    # the stale value reads the same context
+    off1 = int(pos_b[1] % 4)
+    kn_s, vn_s = kn.at[1].set(pk[tail1, off1]), vn.at[1].set(pv[tail1, off1])
+    ctx_s, _, _ = paged_attention_ref(q, kn_s, vn_s, pk, pv, bt, pos_b, None,
+                                      scale=SCALE)
+    np.testing.assert_allclose(ctx_r[1], ctx_s[1], atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(ctx_f, ctx_r, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(ctx_p, ctx_r, atol=1e-5, rtol=1e-5)
 

@@ -141,9 +141,35 @@ def test_engine_spans_nest(monkeypatch):
     assert name == "engine.decode"
     assert [k[0] for k in kids] == ["engine.grow", "engine.dispatch",
                                     "engine.sync", "engine.deliver"]
-    assert kids[1][1] == {"steps": 2, "active": 2}
+    # both slots' positions (5, 6 and 3, 4) lie in their first block
+    assert kids[1][1] == {"steps": 2, "active": 2, "live_blocks": 4}
     assert all(not k[2] for k in kids)
     assert [len(r.out) for r in reqs] == [3, 3]
+
+
+def test_walked_blocks_match_the_served_positions(monkeypatch):
+    """``kv_blocks_walked`` counts, at every decode step, each active slot's
+    blocks through its tail block, and ``kv_blocks_tabled`` its whole table;
+    each ``engine.dispatch`` span carries its chunk's share of the walk."""
+    rec = _Recorder()
+    monkeypatch.setattr(serve, "TraceAnnotation", rec)
+    eng = _engine("digital")  # blocks of 8, 4 a table
+    lens, max_new = (13, 2, 7), (9, 12, 5)
+    reqs = [Request(rid=i, prompt=np.arange(n) % 50, max_new=m)
+            for i, (n, m) in enumerate(zip(lens, max_new))]
+    serve.serve(eng, reqs)
+    assert all(r.done and r.error is None for r in reqs)
+    # a request decodes max_new - 1 tokens (the first comes from prefill),
+    # the j-th at position len(prompt) + j
+    walked = sum(min((n + j) // 8 + 1, eng.max_blocks)
+                 for n, m in zip(lens, max_new) for j in range(m - 1))
+    assert eng.kv_blocks_walked == walked
+    assert eng.kv_blocks_tabled == eng.max_blocks * sum(
+        m - 1 for m in max_new)
+    spans = [args for kind, name, args in rec.events
+             if kind == "enter" and name == "engine.dispatch"]
+    assert len(spans) == eng.decode_calls
+    assert sum(a["live_blocks"] for a in spans) == walked
 
 
 def test_spans_reach_the_profile(tmp_path):

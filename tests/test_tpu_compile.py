@@ -22,6 +22,18 @@ from repro.kernels.ref import AnalyticSpec, BitSerialSpec
 # 8-token blocks; projection K=d_model=1536, M=d_ff=6144
 SLOTS, HKV, G, HD, BS = 8, 24, 1, 64, 8
 K, M = 1536, 6144
+# the paged kernel's served geometries, (slots, Hkv, G, hd, max_blocks):
+# musicgen-medium's MHA at several table sizes (render: 3 slots of 250
+# blocks) and internvl2-2b's GQA (vqa: 14 slots of 288 blocks)
+PAGED = {
+    "64": (SLOTS, HKV, G, HD, 64),
+    "512": (SLOTS, HKV, G, HD, 512),
+    "4096": (SLOTS, HKV, G, HD, 4096),
+    "render": (3, HKV, G, HD, 250),
+    "internvl2-64": (SLOTS, 8, 2, 128, 64),
+    "internvl2-4096": (SLOTS, 8, 2, 128, 4096),
+    "vqa": (14, 8, 2, 128, 288),
+}
 
 
 @pytest.fixture(scope="module")
@@ -47,58 +59,76 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("max_blocks", [64, 512, 4096])
-def test_paged_attention_compiles_for_v5e(one_chip, max_blocks):
-    num_blocks = SLOTS * max_blocks + 1
-    pool = _sds((num_blocks, BS, HKV, HD), jnp.bfloat16, one_chip)
+def _paged_compile(one_chip, geometry):
+    """Compile one kernel call with the pools donated and laid out as the
+    kernel takes them (row-major), so that the program holds the call and
+    nothing XLA adds around it; returns (compiled, bytes of one pool)."""
+    from jax.experimental.layout import Format, Layout
+
+    slots, hkv, g, hd, max_blocks = PAGED[geometry]
+    pool_fmt = Format(Layout(major_to_minor=(0, 1, 2, 3)), one_chip)
+    pool = jax.ShapeDtypeStruct((slots * max_blocks + 1, BS, hkv, hd),
+                                jnp.bfloat16, sharding=pool_fmt)
     args = (
-        _sds((SLOTS, HKV, G, HD), jnp.bfloat16, one_chip),
-        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
-        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
+        _sds((slots, hkv, g, hd), jnp.bfloat16, one_chip),
+        _sds((slots, hkv, hd), jnp.bfloat16, one_chip),
+        _sds((slots, hkv, hd), jnp.bfloat16, one_chip),
         pool, pool,
-        _sds((SLOTS, max_blocks), jnp.int32, one_chip),
-        _sds((SLOTS,), jnp.int32, one_chip),
-        _sds((SLOTS,), jnp.bool_, one_chip),
+        _sds((slots, max_blocks), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,), jnp.bool_, one_chip),
     )
 
     def step(q, kn, vn, pk, pv, bt, pos, active):
         return paged_attention_decode(q, kn, vn, pk, pv, bt, pos, active,
-                                      scale=HD ** -0.5, use_pallas=True,
+                                      scale=hd ** -0.5, use_pallas=True,
                                       interpret=False)
 
-    compiled = jax.jit(step).lower(*args).compile()
+    compiled = jax.jit(step, donate_argnums=(3, 4),
+                       out_shardings=(one_chip, pool_fmt, pool_fmt)
+                       ).lower(*args).compile()
+    # a pool's bytes as it lies in HBM: head rows padded to 128 lanes
+    pool_bytes = 2 * (pool.size // hd) * (-(-hd // 128) * 128)
+    return compiled, pool_bytes
+
+
+@pytest.mark.parametrize("geometry", list(PAGED))
+def test_paged_attention_compiles_for_v5e(one_chip, geometry):
+    """The kernel compiles at each served geometry, and the call adds no
+    temporary as large as one pool: both pools are read and written in
+    place."""
+    compiled, pool_bytes = _paged_compile(one_chip, geometry)
     assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes, (mem, pool_bytes)
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes, (mem, pool_bytes)
+
+
+def _assert_named_and_aliased(one_chip, geometry):
+    import re
+
+    compiled, _ = _paged_compile(one_chip, geometry)
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*/paged_attention/', calls[0])
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(7, \{\}\), "
+                     r"\{2\}: \(8, \{\}\)\}", calls[0])
 
 
 def test_paged_attention_kernel_is_named_and_scoped(one_chip):
     """A profile finds the kernel by its ``paged_attention`` scope, and the
     benchmark's older reader by the pools' aliasing (outputs 1 and 2 alias
-    operands 7 and 8): the compiled custom call carries both."""
-    import re
+    operands 7 and 8): the compiled custom call carries both (musicgen's
+    MHA geometry)."""
+    _assert_named_and_aliased(one_chip, "64")
 
-    max_blocks = 64
-    pool = _sds((SLOTS * max_blocks + 1, BS, HKV, HD), jnp.bfloat16, one_chip)
-    args = (
-        _sds((SLOTS, HKV, G, HD), jnp.bfloat16, one_chip),
-        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
-        _sds((SLOTS, HKV, HD), jnp.bfloat16, one_chip),
-        pool, pool,
-        _sds((SLOTS, max_blocks), jnp.int32, one_chip),
-        _sds((SLOTS,), jnp.int32, one_chip),
-        _sds((SLOTS,), jnp.bool_, one_chip),
-    )
 
-    def step(q, kn, vn, pk, pv, bt, pos, active):
-        return paged_attention_decode(q, kn, vn, pk, pv, bt, pos, active,
-                                      scale=HD ** -0.5, use_pallas=True,
-                                      interpret=False)
-
-    text = jax.jit(step).lower(*args).compile().as_text()
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 1
-    assert re.search(r'op_name="[^"]*/paged_attention/', calls[0])
-    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(7, \{\}\), "
-                     r"\{2\}: \(8, \{\}\)\}", calls[0])
+@pytest.mark.parametrize("geometry", ["render", "vqa"])
+def test_served_paged_kernel_is_named_and_scoped(one_chip, geometry):
+    """The same at each served cell's geometry: render's MHA (3 slots of 250
+    blocks) and vqa's GQA (14 slots of 288 blocks, Hkv 8, G 2, hd 128)."""
+    _assert_named_and_aliased(one_chip, geometry)
 
 
 @pytest.mark.parametrize("hw_prng", [False, True], ids=["counter", "hardware"])
